@@ -1152,6 +1152,8 @@ BENCHES = {}
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     BENCHES.update({f.__name__: f for f in (
         fig10_memory, speedup_time_model, fig9_rlcd, fig2_layer_convergence,
         kernels_microbench, round_engine, tab2_pace_ablation, tab1_fl_accuracy,

@@ -6,6 +6,14 @@ Run:  PYTHONPATH=src python examples/serve_decode.py --arch zamba2-7b
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.launch.serve import main
+from repro.launch import serve
+from repro.launch.cache import use_compile_cache
 
-main()
+
+def main():
+    use_compile_cache()
+    serve.main()
+
+
+if __name__ == "__main__":
+    main()

@@ -34,11 +34,7 @@ LOGICAL_AXES = ("embed", "vocab", "heads", "kv", "qkv_in", "attn_out",
 
 
 def _axis_size(mesh, name: str) -> int:
-    shape = getattr(mesh, "shape", {})
-    try:
-        return int(shape.get(name, 1))
-    except AttributeError:  # Mesh.shape is a mapping in every supported jax
-        return 1
+    return int(getattr(mesh, "shape", {}).get(name, 1))
 
 
 def make_rules(cfg, mesh, *, no_tp: bool = False) -> Rules:
@@ -212,12 +208,10 @@ def shard_client_arrays(mesh, tree):
 
 
 def _current_mesh():
-    try:  # jax >= 0.4.x thread-local physical mesh (set by `with mesh:`)
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # noqa: BLE001 — any jax-internal change means "no mesh"
-        return None
+    """The mesh installed by ``with mesh:``, or None."""
+    from jax._src.mesh import thread_resources
+    m = thread_resources.env.physical_mesh
+    return None if m.empty else m
 
 
 def shard_batch(x, *, batch_axes: Tuple[str, ...] = ("pod", "data")):

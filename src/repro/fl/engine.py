@@ -297,10 +297,11 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
       Both forms are one jit dispatch with the Eq. 1 weighted aggregation
       inside the compiled function and ONE host sync per round.
 
-    The stacked ``batches`` buffer is donated on accelerators — it is
-    rebuilt from host data every round. Params/state are NOT donated because
-    a round may split into several fused cohorts (cached vs recompute
-    groups) that share them.
+    Only the compressed round's carried residuals are donated (on
+    accelerators): no output has the stacked ``batches``' shape, so XLA could
+    not reuse that buffer. Params/state are NOT donated because a round may
+    split into several fused cohorts (cached vs recompute groups) that share
+    them.
 
     ``compute_dtype`` (e.g. ``"bfloat16"``) switches local training to
     mixed precision: each SGD step casts a throwaway copy of the params
@@ -423,6 +424,12 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
                     lsum + jnp.where(live, loss, 0.0)), None
 
         init = (params, state, opt_state, jnp.int32(0), jnp.float32(0.0))
+        if n_shards > 1:
+            # under shard_map the carry starts replicated but each step
+            # mixes in this shard's batch; scan needs the carry's type to
+            # say up front that it varies over the client axis
+            init = jax.tree.map(
+                lambda x: jax.lax.pcast(x, CLIENT_AXIS, to="varying"), init)
         (p, st, _, _, lsum), _ = jax.lax.scan(one, init, batches,
                                               unroll=True if unroll else 1)
         return p, st, lsum / jnp.maximum(nb, 1).astype(jnp.float32)
@@ -727,19 +734,19 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
                 jax.tree.map(psum_agg(w), out_st), losses,
                 jax.tree.unflatten(treedef, new_r))
 
-    # the CPU backend cannot alias donated buffers — donate only where it
-    # helps; the stacked batches (and carried residuals) are rebuilt from
-    # host/per-client state every round, so both are safe to donate
+    # the carried residuals are rebuilt from per-client state every round
+    # and alias the new residuals, so they are donated where the backend
+    # can alias (not CPU)
+    donate_res = (6,) if jax.default_backend() != "cpu" else ()
     if n_shards > 1:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         rep, csp = P(), P(CLIENT_AXIS)
-        donate_ok = jax.default_backend() != "cpu"
         if compress_ratio is not None:
             fn = shard_map(round_fn_compressed_sharded, mesh=mesh,
                            in_specs=(rep, rep, rep, csp, csp, csp, csp),
                            out_specs=(rep, rep, csp, csp))
-            return jax.jit(fn, donate_argnums=(3, 6) if donate_ok else ())
+            return jax.jit(fn, donate_argnums=donate_res)
         if defended:
             # shard_map needs a fixed positional signature, so the codes
             # input only exists on injector-enabled builds
@@ -752,8 +759,7 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
                 in_sp = (rep, rep, rep, csp, csp, csp)
             out_sp = (rep, rep, csp, csp, csp, csp)
             smfn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_sp,
-                                     out_specs=out_sp),
-                           donate_argnums=(3,) if donate_ok else ())
+                                     out_specs=out_sp))
 
             def sharded_defended(params, frozen, state, batches, nb_live,
                                  weights, fault_codes=None):
@@ -771,15 +777,13 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
         fn = shard_map(round_fn_sharded, mesh=mesh,
                        in_specs=(rep, rep, rep, csp, csp, csp),
                        out_specs=(rep, rep, csp))
-        return jax.jit(fn, donate_argnums=(3,) if donate_ok else ())
+        return jax.jit(fn)
     if compress_ratio is not None:
-        donate = (3, 6) if jax.default_backend() != "cpu" else ()
-        return jax.jit(round_fn_compressed, donate_argnums=donate)
-    donate = (3,) if jax.default_backend() != "cpu" else ()
+        return jax.jit(round_fn_compressed, donate_argnums=donate_res)
     if defended and aggregator != "mean":
-        return jax.jit(robust_fn, donate_argnums=donate)
+        return jax.jit(robust_fn)
     if defended and not unroll:
-        observe_jit = jax.jit(observe_vmap, donate_argnums=donate)
+        observe_jit = jax.jit(observe_vmap)
 
         def vmap_defended(params, frozen, state, batches, nb_live, weights,
                           fault_codes=None):
@@ -796,8 +800,7 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
     if defended:
         # unrolled two-dispatch form: the screen probe always runs, and the
         # aggregate comes from the EXACT legacy jit whenever every live row
-        # passed clean. The batches buffer feeds BOTH jits, so it is never
-        # donated here.
+        # passed clean.
         legacy_jit = jax.jit(round_fn)
         probe_jit = jax.jit(train_stacked)
 
@@ -821,7 +824,7 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
             return agg_p, agg_st, losses_p, keep
 
         return unrolled_defended
-    return jax.jit(round_fn, donate_argnums=donate)
+    return jax.jit(round_fn)
 
 
 # ---------------------------------------------------------------------------

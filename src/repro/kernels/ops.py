@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only: the
-kernels execute their bodies in Python via the Pallas interpreter for
-correctness validation; on a TPU backend they compile to Mosaic).
+``interpret`` defaults to True off-TPU (on CPU the kernels execute their
+bodies via the Pallas interpreter, for correctness validation); on a TPU
+backend they compile to Mosaic.
 
 ``flash_attention`` is differentiable: custom_vjp whose backward recomputes
 through the XLA blockwise reference (O(S) memory, exact).

@@ -8,6 +8,10 @@ federated cross-silo axis (DESIGN.md §2).
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+from repro.dist.sharding import CLIENT_AXIS
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,19 +26,30 @@ def make_debug_mesh(n_devices: int = 1):
     return jax.make_mesh((1, n), ("data", "model"))
 
 
-def make_client_mesh(n_devices: int | None = None):
+def make_client_mesh(n_devices: int | None = None, *, devices=None):
     """1-D mesh over the federated cohort axis (``"clients"``).
 
     The fused round engine (``fl/engine.py``) shard_maps the per-client
     local training over this axis: clients partition across devices, params
     replicate, and the Eq. 1 aggregation is one cross-device ``psum``.
-    Defaults to every visible device. CPU testing forces extra host devices
-    with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (set BEFORE
-    jax import — see tests/test_shard.py and ``benchmarks/run.py
-    shard_scale``)."""
-    avail = len(jax.devices())
-    n = avail if n_devices is None else min(n_devices, avail)
-    return jax.make_mesh((max(n, 1),), ("clients",))
+    Defaults to every visible device (or every one of ``devices``, e.g. a
+    described topology's); asking for more than are visible raises. CPU
+    testing forces extra host devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (set BEFORE jax
+    import — see tests/test_shard.py and ``benchmarks/run.py shard_scale``).
+
+    The axis is ``Auto``: arrays placed on the mesh carry no sharding in
+    their types, so jitted code outside the shard_mapped round (feature
+    extraction, host folds) mixes them freely with single-device arrays."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices) if n_devices is None else n_devices
+    if not 1 <= n <= len(devices):
+        raise ValueError(f"client mesh of {n} devices requested, but "
+                         f"{len(devices)} are visible (on CPU, set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N before "
+                         "jax initializes)")
+    return Mesh(np.asarray(devices[:n]), (CLIENT_AXIS,),
+                axis_types=(AxisType.Auto,))
 
 
 # TPU v5e hardware constants (per chip) — §Roofline denominators

@@ -37,6 +37,7 @@ from repro.core.pace import PaceController
 from repro.data.synthetic import make_lm_batch
 from repro.fl.sim import (FederatedLoop, pack_rng_state, tree_like,
                           unpack_rng_state)
+from repro.launch.cache import use_compile_cache
 from repro.models.transformer import build
 from repro.optim import adamw, sgd, warmup_cosine
 
@@ -56,22 +57,13 @@ def train(arch: str, *, reduced: bool = True, steps: int = 40, batch: int = 8,
         # "clients") partitions across devices; make_fed_round_step's
         # vmap-over-pods then runs SPMD under GSPMD with replicated params.
         # Pods that don't divide the axis fall back to single-device
-        # placement (the make_rules divisibility discipline).
-        from repro.dist.sharding import client_axis_size
+        # placement (the make_rules divisibility discipline). Fewer visible
+        # devices than requested raises in make_client_mesh.
         from repro.launch.mesh import make_client_mesh
         mesh = make_client_mesh(mesh_clients)
-        if client_axis_size(mesh) < mesh_clients:
-            # the easy mistake: XLA_FLAGS forcing host devices was not set
-            # before jax initialized, so fewer devices are visible than
-            # requested — say so instead of silently running smaller
-            print(f"--mesh-clients: requested {mesh_clients} devices but "
-                  f"only {client_axis_size(mesh)} visible (set XLA_FLAGS="
-                  "--xla_force_host_platform_device_count=N before jax "
-                  "initializes?)")
-        if num_pods % client_axis_size(mesh) != 0:
+        if num_pods % mesh_clients != 0:
             print(f"--mesh-clients: {num_pods} pods do not divide the "
-                  f"{client_axis_size(mesh)}-device client axis; running "
-                  "replicated")
+                  f"{mesh_clients}-device client axis; running replicated")
             mesh = None
     if reduced:
         over = {}
@@ -222,6 +214,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 40, batch: int = 8,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true", default=True)

@@ -2,11 +2,3 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-try:  # real hypothesis when available (declared in pyproject.toml)
-    import hypothesis  # noqa: F401
-except ImportError:  # hermetic/offline: deterministic seeded-sweep fallback
-    from repro._compat import hypothesis_stub
-
-    sys.modules["hypothesis"] = hypothesis_stub
-    sys.modules["hypothesis.strategies"] = hypothesis_stub.strategies

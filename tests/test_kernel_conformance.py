@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.fl import quant
@@ -274,6 +274,7 @@ def test_sparse_matches_ref_with_duplicates():
 @settings(max_examples=12, deadline=None)
 @given(K=st.integers(1, 6), k=st.integers(1, 32),
        L=st.sampled_from([1, 8, 50, 400]), zero_w=st.booleans())
+@example(K=1, k=1, L=1, zero_w=False)
 def test_sparse_shape_sweep(K, k, L, zero_w):
     """Hypothesis sweep: duplicate and out-of-order indices arise naturally
     from random draws; ``zero_w`` zeroes one client's Eq. 1 weight (a
