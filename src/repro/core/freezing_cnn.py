@@ -68,11 +68,12 @@ def cnn_prefix_features(model: CNN, frozen: Params, bn_state: Params,
     frozen prefix: the identity is returned."""
     if stage == 0:
         return x
-    h = x
-    if model.cfg.kind == "resnet":
-        h, _ = model.stem(frozen, bn_state, h, train=False)
-    h, _ = model.run_stages(frozen, bn_state, h, 0, stage, train=False)
-    return jax.lax.stop_gradient(h)
+    with jax.named_scope("prefix"):
+        h = x
+        if model.cfg.kind == "resnet":
+            h, _ = model.stem(frozen, bn_state, h, train=False)
+        h, _ = model.run_stages(frozen, bn_state, h, 0, stage, train=False)
+        return jax.lax.stop_gradient(h)
 
 
 def cnn_stage_forward_from_features(model: CNN, active: Params,

@@ -56,6 +56,7 @@ from repro.fl.quant import (CACHE_TIERS, EncodedFeatures, cast_floating,
                             make_input_cast_loss, make_tiered_loss,
                             normalize_tier)
 from repro.optim import Optimizer, apply_updates, clip_by_global_norm
+from repro.spans import SpanStats
 
 LossFn = Callable[[Any, Any, Any, Dict], Tuple[jnp.ndarray, Any]]
 #   loss_fn(params, frozen, state, batch) -> (loss, new_state)
@@ -76,15 +77,17 @@ LossFn = Callable[[Any, Any, Any, Dict], Tuple[jnp.ndarray, Any]]
 
 
 def _weighted_avg_products(trees: Tuple, w):
-    return tuple(jax.tree.map(lambda x: x.astype(jnp.float32) * w[i], t)
-                 for i, t in enumerate(trees))
+    with jax.named_scope("fold"):
+        return tuple(jax.tree.map(lambda x: x.astype(jnp.float32) * w[i], t)
+                     for i, t in enumerate(trees))
 
 
 def _weighted_avg_sum(prods: Tuple, ref):
-    out = prods[0]
-    for p in prods[1:]:
-        out = jax.tree.map(jnp.add, out, p)  # left fold, no reassociation
-    return jax.tree.map(lambda a, r: a.astype(r.dtype), out, ref)
+    with jax.named_scope("fold"):
+        out = prods[0]
+        for p in prods[1:]:
+            out = jax.tree.map(jnp.add, out, p)  # left fold, no reassociation
+        return jax.tree.map(lambda a, r: a.astype(r.dtype), out, ref)
 
 
 _wavg_products_jit = jax.jit(_weighted_avg_products)
@@ -396,53 +399,62 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
     loss_fn = make_input_cast_loss(loss_fn, compute_dtype)
 
     def local_train(params, frozen, state, batches, nb):
-        opt_state = optimizer.init(params)  # f32 master-weight state
-        if cdt is not None:
-            frozen = cast_floating(frozen, cdt)
+        with jax.named_scope("local_train"):
+            opt_state = optimizer.init(params)  # f32 master-weight state
+            if cdt is not None:
+                frozen = cast_floating(frozen, cdt)
 
-        def one(carry, batch):
-            p, st, ost, t, lsum = carry
-            if cdt is None:
-                (loss, st2), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    p, frozen, st, batch)
-            else:
-                (loss, st2), grads = jax.value_and_grad(
-                    lambda pc: loss_fn(pc, frozen, st, batch),
-                    has_aux=True)(cast_floating(p, cdt))
-                grads = jax.tree.map(lambda g, m: g.astype(m.dtype), grads, p)
-                st2 = jax.tree.map(lambda a, m: a.astype(m.dtype), st2, st)
-                loss = loss.astype(jnp.float32)
-            grads, _ = clip_by_global_norm(grads, clip_norm)
-            ups, ost2 = optimizer.update(grads, ost, p)
-            p2 = apply_updates(p, ups)
-            live = t < nb
+            def one(carry, batch):
+                p, st, ost, t, lsum = carry
+                if cdt is None:
+                    (loss, st2), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(p, frozen, st, batch)
+                else:
+                    (loss, st2), grads = jax.value_and_grad(
+                        lambda pc: loss_fn(pc, frozen, st, batch),
+                        has_aux=True)(cast_floating(p, cdt))
+                    grads = jax.tree.map(lambda g, m: g.astype(m.dtype),
+                                         grads, p)
+                    st2 = jax.tree.map(lambda a, m: a.astype(m.dtype),
+                                       st2, st)
+                    loss = loss.astype(jnp.float32)
+                grads, _ = clip_by_global_norm(grads, clip_norm)
+                ups, ost2 = optimizer.update(grads, ost, p)
+                p2 = apply_updates(p, ups)
+                live = t < nb
 
-            def pick(new, old):
-                return jax.tree.map(lambda a, b: jnp.where(live, a, b), new, old)
+                def pick(new, old):
+                    return jax.tree.map(lambda a, b: jnp.where(live, a, b),
+                                        new, old)
 
-            return (pick(p2, p), pick(st2, st), pick(ost2, ost), t + 1,
-                    lsum + jnp.where(live, loss, 0.0)), None
+                return (pick(p2, p), pick(st2, st), pick(ost2, ost), t + 1,
+                        lsum + jnp.where(live, loss, 0.0)), None
 
-        init = (params, state, opt_state, jnp.int32(0), jnp.float32(0.0))
-        if n_shards > 1:
-            # under shard_map the carry starts replicated but each step
-            # mixes in this shard's batch; scan needs the carry's type to
-            # say up front that it varies over the client axis
-            init = jax.tree.map(
-                lambda x: jax.lax.pcast(x, CLIENT_AXIS, to="varying"), init)
-        (p, st, _, _, lsum), _ = jax.lax.scan(one, init, batches,
-                                              unroll=True if unroll else 1)
-        return p, st, lsum / jnp.maximum(nb, 1).astype(jnp.float32)
+            init = (params, state, opt_state, jnp.int32(0),
+                    jnp.float32(0.0))
+            if n_shards > 1:
+                # under shard_map the carry starts replicated but each step
+                # mixes in this shard's batch; scan needs the carry's type
+                # to say up front that it varies over the client axis
+                init = jax.tree.map(
+                    lambda x: jax.lax.pcast(x, CLIENT_AXIS, to="varying"),
+                    init)
+            (p, st, _, _, lsum), _ = jax.lax.scan(one, init, batches,
+                                                  unroll=True if unroll else 1)
+            return p, st, lsum / jnp.maximum(nb, 1).astype(jnp.float32)
 
     def make_agg(w):
         def agg(x):
-            return jnp.einsum("k,k...->...", w,
-                              x.astype(jnp.float32)).astype(x.dtype)
+            with jax.named_scope("fold"):
+                return jnp.einsum("k,k...->...", w,
+                                  x.astype(jnp.float32)).astype(x.dtype)
         return agg
 
     def wsum(acc, tree, wi):
-        contrib = jax.tree.map(lambda b: wi * b.astype(jnp.float32), tree)
-        return contrib if acc is None else jax.tree.map(jnp.add, acc, contrib)
+        with jax.named_scope("fold"):
+            contrib = jax.tree.map(lambda b: wi * b.astype(jnp.float32), tree)
+            return (contrib if acc is None
+                    else jax.tree.map(jnp.add, acc, contrib))
 
     def cast_like(acc, ref):
         return jax.tree.map(lambda a, r: a.astype(r.dtype), acc, ref)
@@ -570,14 +582,17 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
         sort to +inf and the order statistics index the valid prefix)."""
         out_p, out_st, losses, keep = train_stacked(
             params, frozen, state, batches, nb_live, weights, fault_codes)
-        n_valid = jnp.sum(keep.astype(jnp.int32))
-        safe = n_valid > 0
-        rob = lambda x: _robust_leaf(x, keep, n_valid, aggregator, trim_beta)
-        # all rows screened out -> the round is a no-op (never average NaN)
-        agg_p = jax.tree.map(lambda x, p0: jnp.where(safe, rob(x), p0),
-                             out_p, params)
-        agg_st = jax.tree.map(lambda x, s0: jnp.where(safe, rob(x), s0),
-                              out_st, state)
+        with jax.named_scope("fold"):
+            n_valid = jnp.sum(keep.astype(jnp.int32))
+            safe = n_valid > 0
+            rob = lambda x: _robust_leaf(x, keep, n_valid, aggregator,
+                                         trim_beta)
+            # all rows screened out -> the round is a no-op (never average
+            # NaN)
+            agg_p = jax.tree.map(lambda x, p0: jnp.where(safe, rob(x), p0),
+                                 out_p, params)
+            agg_st = jax.tree.map(lambda x, s0: jnp.where(safe, rob(x), s0),
+                                  out_st, state)
         return agg_p, agg_st, losses, keep
 
     def round_fn_compressed(params, frozen, state, batches, nb_live, weights,
@@ -610,22 +625,24 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
                     if use_pallas:
                         sent_rows[j].append((idx, vals))
                     else:
-                        agg_acc[j] = agg_acc[j].at[idx].add(w[i] * vals)
+                        with jax.named_scope("fold"):
+                            agg_acc[j] = agg_acc[j].at[idx].add(w[i] * vals)
                     # residual = delta - sent: the kept entries were
                     # transmitted exactly, so they zero out
                     new_r_rows[j].append(delta.at[idx].set(0.0))
                 agg_st = wsum(agg_st, st_i, w[i])
                 losses.append(loss_i)
-            if use_pallas:
-                agg_acc = [
-                    ingraph_sparse_aggregate(
-                        jnp.stack([i_ for i_, _ in rows]),
-                        jnp.stack([v_ for _, v_ in rows]), w, p0.size,
-                        use_pallas=True)
-                    for p0, rows in zip(p_leaves, sent_rows)]
-            new_p = [(p0.astype(jnp.float32).reshape(-1) + acc)
-                     .reshape(p0.shape).astype(p0.dtype)
-                     for p0, acc in zip(p_leaves, agg_acc)]
+            with jax.named_scope("fold"):
+                if use_pallas:
+                    agg_acc = [
+                        ingraph_sparse_aggregate(
+                            jnp.stack([i_ for i_, _ in rows]),
+                            jnp.stack([v_ for _, v_ in rows]), w, p0.size,
+                            use_pallas=True)
+                        for p0, rows in zip(p_leaves, sent_rows)]
+                new_p = [(p0.astype(jnp.float32).reshape(-1) + acc)
+                         .reshape(p0.shape).astype(p0.dtype)
+                         for p0, acc in zip(p_leaves, agg_acc)]
             return (jax.tree.unflatten(treedef, new_p),
                     cast_like(agg_st, state), jnp.stack(losses),
                     jax.tree.unflatten(treedef, [jnp.stack(rows)
@@ -637,10 +654,11 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
             batches, nb_live)
         new_p, new_r = [], []
         for p0, pk, r in zip(p_leaves, jax.tree.leaves(out_p), r_leaves):
-            agg_flat, r_new, _, _ = ingraph_compress_leaf(
-                p0.astype(jnp.float32).reshape(-1),
-                pk.astype(jnp.float32).reshape(K, -1), r, w, compress_ratio,
-                use_pallas=use_pallas)
+            with jax.named_scope("fold"):
+                agg_flat, r_new, _, _ = ingraph_compress_leaf(
+                    p0.astype(jnp.float32).reshape(-1),
+                    pk.astype(jnp.float32).reshape(K, -1), r, w,
+                    compress_ratio, use_pallas=use_pallas)
             new_p.append(agg_flat.reshape(p0.shape).astype(p0.dtype))
             new_r.append(r_new)
         # mutable state (BN stats) stays a dense server-side average — only
@@ -653,8 +671,9 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
 
     def psum_agg(w):
         def agg(x):
-            part = jnp.einsum("k,k...->...", w, x.astype(jnp.float32))
-            return jax.lax.psum(part, CLIENT_AXIS).astype(x.dtype)
+            with jax.named_scope("fold"):
+                part = jnp.einsum("k,k...->...", w, x.astype(jnp.float32))
+                return jax.lax.psum(part, CLIENT_AXIS).astype(x.dtype)
         return agg
 
     def shard_train(params, frozen, state, batches, nb_live, weights):
@@ -721,12 +740,15 @@ def make_fused_round(loss_fn: LossFn, optimizer: Optimizer, *,
         for p0, pk, r in zip(p_leaves, jax.tree.leaves(out_p),
                              jax.tree.leaves(residuals)):
             p0_flat = p0.astype(jnp.float32).reshape(-1)
-            agg_local, r_new, _, _ = ingraph_compress_leaf(
-                p0_flat, pk.astype(jnp.float32).reshape(K, -1), r, w,
-                compress_ratio)
-            # agg_local = p0 + this shard's weighted sparse scatter-add;
-            # the global Eq. 1 aggregate joins the partials with one psum
-            agg = p0_flat + jax.lax.psum(agg_local - p0_flat, CLIENT_AXIS)
+            with jax.named_scope("fold"):
+                agg_local, r_new, _, _ = ingraph_compress_leaf(
+                    p0_flat, pk.astype(jnp.float32).reshape(K, -1), r, w,
+                    compress_ratio)
+                # agg_local = p0 + this shard's weighted sparse scatter-add;
+                # the global Eq. 1 aggregate joins the partials with one
+                # psum
+                agg = p0_flat + jax.lax.psum(agg_local - p0_flat,
+                                             CLIENT_AXIS)
             new_p.append(agg.reshape(p0.shape).astype(p0.dtype))
             new_r.append(r_new)
         # BN state stays a dense weighted average (params-only uplink)
@@ -887,6 +909,14 @@ class RoundEngine:
     bit-identical to an undefended engine (the legacy code paths are used
     verbatim whenever no defense is active). None of this composes with
     ``compress_ratio`` (error feedback would carry corrupted signal).
+
+    ``spans`` (``repro.spans.SpanStats``) times the fused round's host
+    steps: ``engine.round`` (all of ``run_round``), ``engine.gather``
+    (batch plans, indexing, ``np.stack``, padding), ``engine.put`` (host
+    arrays to the device, their bytes counted in ``engine.h2d_bytes``),
+    ``engine.dispatch`` (the compiled call), ``engine.sync`` (the one
+    blocking read), ``engine.combine`` (the host fold of several tier
+    groups) and ``engine.features`` (a frozen-prefix extraction).
     """
     loss_fn: LossFn
     optimizer: Optimizer
@@ -915,6 +945,7 @@ class RoundEngine:
     _jit_cache: Dict[str, Callable] = field(default_factory=dict, repr=False)
     _res_pool: List = field(default_factory=list, repr=False)   # per leaf [cap, L]
     _res_row: Dict[int, int] = field(default_factory=dict, repr=False)
+    spans: SpanStats = field(default_factory=SpanStats, repr=False)
 
     # ----- frozen-prefix feature cache (tiered) -----
 
@@ -927,8 +958,9 @@ class RoundEngine:
         enc = self._features.get(client.client_id)
         if enc is None or enc.tier != tier:
             fn = self._jit_cache.setdefault("feature", jax.jit(self.feature_fn))
-            enc = encode_features(
-                np.asarray(fn(jnp.asarray(client.data["x"]))), tier)
+            with self.spans.span("engine.features"):
+                enc = encode_features(
+                    np.asarray(fn(jnp.asarray(client.data["x"]))), tier)
             self._features[client.client_id] = enc
             self._cache_version += 1
         return enc
@@ -1092,6 +1124,13 @@ class RoundEngine:
         updates are corrupted (delta-space) before screening/aggregation;
         crash/hang kinds never reach the engine (the aggregation policies
         drop those clients upstream)."""
+        with self.spans.span("engine.round"):
+            return self._run_round(clients, selected, params, state,
+                                   round_idx, use_cache=use_cache,
+                                   sequential=sequential, faults=faults)
+
+    def _run_round(self, clients, selected, params, state, round_idx, *,
+                   use_cache, sequential, faults):
         use_cache = use_cache or {}
         seq = (not self.fused) if sequential is None else sequential
         if self.aggregator not in AGGREGATORS:
@@ -1123,8 +1162,9 @@ class RoundEngine:
             return partials[0][0], partials[0][1], losses
         w = np.asarray([p[2] for p in partials], np.float64)
         w /= w.sum()
-        return (weighted_avg([p[0] for p in partials], w),
-                weighted_avg([p[1] for p in partials], w), losses)
+        with self.spans.span("engine.combine"):
+            return (weighted_avg([p[0] for p in partials], w),
+                    weighted_avg([p[1] for p in partials], w), losses)
 
     # ----- fused path -----
 
@@ -1149,39 +1189,25 @@ class RoundEngine:
         codes = corrupt_codes(faults, cids)
         defended = (self.screen or self.aggregator != "mean"
                     or codes is not None)
-        bs, ep = self.batch_size, self.local_epochs
-        plans = {cid: batch_index_plan(clients[cid].num_samples, bs, ep,
-                                       clients[cid].round_seed(round_idx))
-                 for cid in cids}
-        nb_live = np.asarray([len(plans[cid]) for cid in cids], np.int32)
-        nb = max(int(nb_live.max()), 1)
-        stacked: Dict[str, np.ndarray] = {}
-        sample = self._client_arrays(clients[cids[0]], tier)
-        for key in sample:
-            rows = []
-            for cid in cids:
-                data = self._client_arrays(clients[cid], tier)[key]
-                plan = plans[cid]
-                # pad exhausted clients by cycling their plan (masked anyway)
-                idx = np.stack([plan[t % len(plan)] if plan
-                                else np.zeros(bs, np.int64)
-                                for t in range(nb)])
-                rows.append(data[idx])
-            stacked[key] = np.stack(rows)
-        weights = np.asarray([clients[cid].num_samples for cid in cids],
-                             np.float32)
         n_shards = client_axis_size(self.mesh)
         pad = (-len(cids)) % n_shards if n_shards > 1 else 0
-        if pad:
-            # pad the cohort to a multiple of the client-axis size with
-            # inert rows: nb_live=0 masks every local step and weight=0
-            # zeroes the Eq. 1 contribution, so padded row CONTENT is never
-            # consumed (first row repeated only to keep shapes/dtypes)
-            stacked = {k: np.concatenate([v, np.repeat(v[:1], pad, axis=0)])
-                       for k, v in stacked.items()}
-            nb_live = np.concatenate([nb_live, np.zeros(pad, np.int32)])
-        w_in = (np.concatenate([weights, np.zeros(pad, np.float32)])
-                if pad else weights)
+        with self.spans.span("engine.gather"):
+            stacked, nb_live, weights = self._gather(clients, cids,
+                                                     round_idx, tier)
+            if pad:
+                # pad the cohort to a multiple of the client-axis size with
+                # inert rows: nb_live=0 masks every local step and weight=0
+                # zeroes the Eq. 1 contribution, so padded row CONTENT is
+                # never consumed (first row repeated only to keep
+                # shapes/dtypes)
+                stacked = {k: np.concatenate([v, np.repeat(v[:1], pad,
+                                                           axis=0)])
+                           for k, v in stacked.items()}
+                nb_live = np.concatenate([nb_live, np.zeros(pad, np.int32)])
+            w_in = (np.concatenate([weights, np.zeros(pad, np.float32)])
+                    if pad else weights)
+            if codes is not None and pad:
+                codes = np.concatenate([codes, np.zeros(pad, np.int32)])
         key = "fused" if tier is None else f"fused_cached_{tier}"
         if self.use_pallas:
             key += "|pallas"
@@ -1210,63 +1236,102 @@ class RoundEngine:
             self._jit_cache[key] = fn
         cached = tier is not None
         frozen = {} if cached else (self.frozen if self.frozen is not None else {})
-        batches = {k: jnp.asarray(v) for k, v in stacked.items()}
-        nb_dev, w_dev = jnp.asarray(nb_live), jnp.asarray(w_in)
-        codes_dev = None
-        if codes is not None:
-            codes_dev = jnp.asarray(np.concatenate(
-                [codes, np.zeros(pad, np.int32)]) if pad else codes)
-        if n_shards > 1:
-            # explicit placement: cohort-stacked rows partition along the
-            # client axis, model trees replicate — no implicit resharding
-            # inside the dispatch
-            params, frozen, state = replicate(self.mesh,
-                                              (params, frozen, state))
-            batches, nb_dev, w_dev = shard_cohort(self.mesh,
-                                                  (batches, nb_dev, w_dev))
-            if codes_dev is not None:
-                codes_dev = shard_cohort(self.mesh, codes_dev)
+        with self.spans.span("engine.put"):
+            host = [*stacked.values(), nb_live, w_in]
+            if codes is not None:
+                host.append(codes)
+            self.spans.add("engine.h2d_bytes", sum(a.nbytes for a in host))
+            batches = {k: jnp.asarray(v) for k, v in stacked.items()}
+            nb_dev, w_dev = jnp.asarray(nb_live), jnp.asarray(w_in)
+            codes_dev = None if codes is None else jnp.asarray(codes)
+            if n_shards > 1:
+                # explicit placement: cohort-stacked rows partition along
+                # the client axis, model trees replicate — no implicit
+                # resharding inside the dispatch
+                params, frozen, state = replicate(self.mesh,
+                                                  (params, frozen, state))
+                batches, nb_dev, w_dev = shard_cohort(
+                    self.mesh, (batches, nb_dev, w_dev))
+                if codes_dev is not None:
+                    codes_dev = shard_cohort(self.mesh, codes_dev)
         args = (params, frozen, state, batches, nb_dev, w_dev)
-        if self.compress_ratio is not None:
-            residuals, rows = self._gather_residuals(cids, params)
-            if pad:
-                residuals = jax.tree.map(
-                    lambda r: jnp.concatenate(
-                        [r, jnp.zeros((pad, r.shape[1]), r.dtype)]),
-                    residuals)
-            if n_shards > 1:
-                residuals = shard_cohort(self.mesh, residuals)
-            p_g, s_g, l_g, new_r = fn(*args, residuals)
-            if pad:
-                new_r = jax.tree.map(lambda r: r[:len(cids)], new_r)
-            if n_shards > 1:
-                # bring the sharded residual rows back to the resident
-                # single-device pools (one host round-trip per round; the
-                # pools themselves are not sharded — they index by client
-                # id, not cohort slot)
-                new_r = jax.tree.map(lambda r: jnp.asarray(np.asarray(r)),
-                                     new_r)
-            self._scatter_residuals(rows, new_r)
-        else:
-            out = fn(*args, codes_dev) if codes_dev is not None else fn(*args)
-            if defended:
-                # every defended build returns a uniform 4-tuple; the mean
-                # builds are host wrappers that already recombined the kept
-                # rows whenever screening fired
-                p_g, s_g, l_g, keep = out
-                if self.screen:
-                    k_host = np.asarray(keep)[:len(cids)]
-                    # True == this client's update was screened OUT
-                    self.last_screened.update(
-                        {cid: not bool(k_host[i])
-                         for i, cid in enumerate(cids)})
+        with self.spans.span("engine.dispatch"):
+            if self.compress_ratio is not None:
+                p_g, s_g, l_g = self._dispatch_compressed(fn, args, cids, pad)
             else:
-                p_g, s_g, l_g = out
+                out = (fn(*args, codes_dev) if codes_dev is not None
+                       else fn(*args))
+                if defended:
+                    # every defended build returns a uniform 4-tuple; the
+                    # mean builds are host wrappers that already recombined
+                    # the kept rows whenever screening fired
+                    p_g, s_g, l_g, keep = out
+                    if self.screen:
+                        k_host = np.asarray(keep)[:len(cids)]
+                        # True == this client's update was screened OUT
+                        self.last_screened.update(
+                            {cid: not bool(k_host[i])
+                             for i, cid in enumerate(cids)})
+                else:
+                    p_g, s_g, l_g = out
         self.last_uplink_bytes += self._uplink_bytes(params, len(cids))
         # ONE blocking sync for the whole cohort (padded rows sliced off)
-        l_host = np.asarray(l_g)[:len(cids)]
+        with self.spans.span("engine.sync"):
+            l_host = np.asarray(l_g)[:len(cids)]
         return (p_g, s_g, {cid: float(l_host[i]) for i, cid in enumerate(cids)},
                 float(weights.sum()))
+
+    def _gather(self, clients, cids, round_idx, tier):
+        """The cohort's minibatch sequences stacked on the host along a
+        leading client axis (exhausted clients cycle their plan; their
+        steps are masked), with the live step counts and Eq. 1 weights."""
+        bs, ep = self.batch_size, self.local_epochs
+        plans = {cid: batch_index_plan(clients[cid].num_samples, bs, ep,
+                                       clients[cid].round_seed(round_idx))
+                 for cid in cids}
+        nb_live = np.asarray([len(plans[cid]) for cid in cids], np.int32)
+        nb = max(int(nb_live.max()), 1)
+        stacked: Dict[str, np.ndarray] = {}
+        sample = self._client_arrays(clients[cids[0]], tier)
+        for key in sample:
+            rows = []
+            for cid in cids:
+                data = self._client_arrays(clients[cid], tier)[key]
+                plan = plans[cid]
+                # pad exhausted clients by cycling their plan (masked anyway)
+                idx = np.stack([plan[t % len(plan)] if plan
+                                else np.zeros(bs, np.int64)
+                                for t in range(nb)])
+                rows.append(data[idx])
+            stacked[key] = np.stack(rows)
+        weights = np.asarray([clients[cid].num_samples for cid in cids],
+                             np.float32)
+        return stacked, nb_live, weights
+
+    def _dispatch_compressed(self, fn, args, cids, pad):
+        """The compressed round: gather the cohort's error-feedback rows,
+        run ``fn``, scatter the new rows back to the resident pools."""
+        residuals, rows = self._gather_residuals(cids, args[0])
+        if pad:
+            residuals = jax.tree.map(
+                lambda r: jnp.concatenate(
+                    [r, jnp.zeros((pad, r.shape[1]), r.dtype)]),
+                residuals)
+        n_shards = client_axis_size(self.mesh)
+        if n_shards > 1:
+            residuals = shard_cohort(self.mesh, residuals)
+        p_g, s_g, l_g, new_r = fn(*args, residuals)
+        if pad:
+            new_r = jax.tree.map(lambda r: r[:len(cids)], new_r)
+        if n_shards > 1:
+            # bring the sharded residual rows back to the resident
+            # single-device pools (one host round-trip per round; the
+            # pools themselves are not sharded — they index by client
+            # id, not cohort slot)
+            new_r = jax.tree.map(lambda r: jnp.asarray(np.asarray(r)),
+                                 new_r)
+        self._scatter_residuals(rows, new_r)
+        return p_g, s_g, l_g
 
     # ----- sequential escape hatch (deadline/straggler path) -----
 
