@@ -910,10 +910,18 @@ class RoundEngine:
     verbatim whenever no defense is active). None of this composes with
     ``compress_ratio`` (error feedback would carry corrupted signal).
 
+    The fused path stages each cohort on the host in buffers the engine
+    keeps per (tier, data key) and reuses from round to round: one copy of
+    each client's minibatch rows into warm pages, reallocated only when
+    the cohort's shape or dtype changes. A buffer is rewritten only after
+    the round that last sent it has synced.
+
     ``spans`` (``repro.spans.SpanStats``) times the fused round's host
     steps: ``engine.round`` (all of ``run_round``), ``engine.gather``
-    (batch plans, indexing, ``np.stack``, padding), ``engine.put`` (host
-    arrays to the device, their bytes counted in ``engine.h2d_bytes``),
+    (batch plans, the copy into the staging buffers, mesh pad rows; each
+    buffer allocated counted in ``engine.stage_alloc_bytes``),
+    ``engine.put`` (host arrays to the device, their bytes counted in
+    ``engine.h2d_bytes``),
     ``engine.dispatch`` (the compiled call), ``engine.sync`` (the one
     blocking read), ``engine.combine`` (the host fold of several tier
     groups) and ``engine.features`` (a frozen-prefix extraction).
@@ -945,6 +953,9 @@ class RoundEngine:
     _jit_cache: Dict[str, Callable] = field(default_factory=dict, repr=False)
     _res_pool: List = field(default_factory=list, repr=False)   # per leaf [cap, L]
     _res_row: Dict[int, int] = field(default_factory=dict, repr=False)
+    # host staging buffers of the fused path, per (tier, data key)
+    _stage: Dict[Tuple[Optional[str], str], np.ndarray] = field(
+        default_factory=dict, repr=False)
     spans: SpanStats = field(default_factory=SpanStats, repr=False)
 
     # ----- frozen-prefix feature cache (tiered) -----
@@ -1193,16 +1204,10 @@ class RoundEngine:
         pad = (-len(cids)) % n_shards if n_shards > 1 else 0
         with self.spans.span("engine.gather"):
             stacked, nb_live, weights = self._gather(clients, cids,
-                                                     round_idx, tier)
+                                                     round_idx, tier, pad)
             if pad:
-                # pad the cohort to a multiple of the client-axis size with
-                # inert rows: nb_live=0 masks every local step and weight=0
-                # zeroes the Eq. 1 contribution, so padded row CONTENT is
-                # never consumed (first row repeated only to keep
-                # shapes/dtypes)
-                stacked = {k: np.concatenate([v, np.repeat(v[:1], pad,
-                                                           axis=0)])
-                           for k, v in stacked.items()}
+                # the pad rows are inert: nb_live=0 masks every local step
+                # and weight=0 zeroes the Eq. 1 contribution
                 nb_live = np.concatenate([nb_live, np.zeros(pad, np.int32)])
             w_in = (np.concatenate([weights, np.zeros(pad, np.float32)])
                     if pad else weights)
@@ -1236,6 +1241,9 @@ class RoundEngine:
             self._jit_cache[key] = fn
         cached = tier is not None
         frozen = {} if cached else (self.frozen if self.frozen is not None else {})
+        # the group's buffers leave the pool until the sync: a round that
+        # raises before it leaves them out, so the next allocates afresh
+        in_flight = {(tier, k): self._stage.pop((tier, k)) for k in stacked}
         with self.spans.span("engine.put"):
             host = [*stacked.values(), nb_live, w_in]
             if codes is not None:
@@ -1278,35 +1286,61 @@ class RoundEngine:
         # ONE blocking sync for the whole cohort (padded rows sliced off)
         with self.spans.span("engine.sync"):
             l_host = np.asarray(l_g)[:len(cids)]
+        self._stage.update(in_flight)
         return (p_g, s_g, {cid: float(l_host[i]) for i, cid in enumerate(cids)},
                 float(weights.sum()))
 
-    def _gather(self, clients, cids, round_idx, tier):
-        """The cohort's minibatch sequences stacked on the host along a
-        leading client axis (exhausted clients cycle their plan; their
-        steps are masked), with the live step counts and Eq. 1 weights."""
+    def _gather(self, clients, cids, round_idx, tier, pad=0):
+        """The cohort's minibatch sequences stacked along a leading client
+        axis, each client's rows taken with one copy into the engine's
+        staging buffer for the key (exhausted clients cycle their plan;
+        their steps are masked), then ``pad`` rows repeating row 0 for the
+        client mesh; with the live step counts and Eq. 1 weights."""
         bs, ep = self.batch_size, self.local_epochs
-        plans = {cid: batch_index_plan(clients[cid].num_samples, bs, ep,
-                                       clients[cid].round_seed(round_idx))
-                 for cid in cids}
-        nb_live = np.asarray([len(plans[cid]) for cid in cids], np.int32)
+        plans = [batch_index_plan(clients[cid].num_samples, bs, ep,
+                                  clients[cid].round_seed(round_idx))
+                 for cid in cids]
+        nb_live = np.asarray([len(plan) for plan in plans], np.int32)
         nb = max(int(nb_live.max()), 1)
+        idx = np.zeros((len(cids), nb * bs), np.int64)
+        for j, plan in enumerate(plans):
+            if plan:
+                idx[j] = np.concatenate(
+                    [plan[t % len(plan)] for t in range(nb)])
+        arrays = [self._client_arrays(clients[cid], tier) for cid in cids]
         stacked: Dict[str, np.ndarray] = {}
-        sample = self._client_arrays(clients[cids[0]], tier)
-        for key in sample:
-            rows = []
-            for cid in cids:
-                data = self._client_arrays(clients[cid], tier)[key]
-                plan = plans[cid]
-                # pad exhausted clients by cycling their plan (masked anyway)
-                idx = np.stack([plan[t % len(plan)] if plan
-                                else np.zeros(bs, np.int64)
-                                for t in range(nb)])
-                rows.append(data[idx])
-            stacked[key] = np.stack(rows)
+        for key in arrays[0]:
+            cols = [a[key] for a in arrays]
+            buf = self._stage_buffer(
+                (tier, key), (len(cids) + pad, nb, bs) + cols[0].shape[1:],
+                np.result_type(*{c.dtype for c in cols}))
+            rows = buf.reshape((len(buf), nb * bs) + buf.shape[3:])
+            for j, data in enumerate(cols):
+                # the plan's indices lie below ``num_samples``, so on an
+                # array that long "clip" takes the rows "raise" would,
+                # without buffering ``out``
+                if len(data) < clients[cids[j]].num_samples:
+                    raise IndexError(f"client {cids[j]}'s {key!r} has "
+                                     f"{len(data)} rows, fewer than its "
+                                     f"{clients[cids[j]].num_samples} "
+                                     f"samples")
+                np.take(np.asarray(data, buf.dtype), idx[j], axis=0,
+                        mode="clip", out=rows[j])
+            buf[len(cids):] = buf[0]
+            stacked[key] = buf
         weights = np.asarray([clients[cid].num_samples for cid in cids],
                              np.float32)
         return stacked, nb_live, weights
+
+    def _stage_buffer(self, slot, shape, dtype) -> np.ndarray:
+        """The staging buffer for ``slot``, allocated anew only when the
+        cohort's shape or dtype changes."""
+        buf = self._stage.get(slot)
+        if buf is None or buf.shape != shape or buf.dtype != dtype:
+            buf = np.empty(shape, dtype)
+            self._stage[slot] = buf
+            self.spans.add("engine.stage_alloc_bytes", buf.nbytes)
+        return buf
 
     def _dispatch_compressed(self, fn, args, cids, pad):
         """The compressed round: gather the cohort's error-feedback rows,

@@ -112,6 +112,21 @@ def main():
     report["pad_params_allclose"] = tree_close(a0, a1)
     report["pad_losses_allclose"] = bool(
         all(abs(l0[c] - l1[c]) < 1e-3 for c in sel[:3]))
+    # the staging buffers hold the 5 pad rows too: allocated in the first
+    # padded round alone, with the bytes put unchanged
+    nb = max(by_id[c].num_samples // 32 for c in sel[:3])
+    x, y = by_id[sel[0]].data["x"], by_id[sel[0]].data["y"]
+    rows = 8 * nb * 32
+    staged = rows * (x[0].nbytes + y[0].nbytes)
+    first = e1.spans.snapshot()["counters"]
+    e1.spans.reset()
+    e1.run_round(by_id, sel[:3], a1, s1, 6)
+    second = e1.spans.snapshot()["counters"]
+    report["pad_staged_once"] = (
+        first.get("engine.stage_alloc_bytes") == staged
+        and "engine.stage_alloc_bytes" not in second
+        and first["engine.h2d_bytes"] == second["engine.h2d_bytes"]
+        == staged + 8 * (4 + 4))
 
     # --- tiered cache gathers under shard_map (int8 dequant in-graph) ---
     e0, active1 = engine(None, stage=1)

@@ -152,6 +152,12 @@ def test_cohort_smaller_than_mesh_padding(shard_report):
     assert shard_report["pad_losses_allclose"]
 
 
+def test_padded_cohort_is_staged_once(shard_report):
+    """The mesh's pad rows live in the reused staging buffers: allocated
+    in the first padded round, none in the next, the same bytes put."""
+    assert shard_report["pad_staged_once"]
+
+
 @pytest.mark.slow
 def test_tiered_cache_sharded(shard_report):
     assert shard_report["tiered_cache_allclose"]

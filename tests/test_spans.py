@@ -118,7 +118,8 @@ def _engine(model, stage, frozen, state, batch_size):
 def test_fused_round_records_each_span_once():
     """One undivided cohort: each engine span closes once a round, the
     children within ``engine.round``, and the bytes counted are those of
-    the stacked batches, live step counts and weights."""
+    the stacked batches, live step counts and weights. The staging
+    buffers of the stacked batches are allocated in round 0 alone."""
     by_id, model, params, state = _world()
     frozen, active = fz.init_cnn_stage_active(model, params, 0,
                                               jax.random.PRNGKey(1))
@@ -139,9 +140,10 @@ def test_fused_round_records_each_span_once():
         nb = max(by_id[c].num_samples // bs for c in cids)
         x, y = by_id[cids[0]].data["x"], by_id[cids[0]].data["y"]
         rows = len(cids) * nb * bs
-        want = (rows * x[0].nbytes + rows * y[0].nbytes
-                + len(cids) * (4 + 4))          # int32 steps, f32 weights
-        assert snap["counters"] == {"engine.h2d_bytes": want}
+        staged = rows * x[0].nbytes + rows * y[0].nbytes
+        want = staged + len(cids) * (4 + 4)     # int32 steps, f32 weights
+        allocs = {"engine.stage_alloc_bytes": staged} if r == 0 else {}
+        assert snap["counters"] == {"engine.h2d_bytes": want, **allocs}
 
 
 def test_mixed_tiers_combine_and_extract_features():
