@@ -92,12 +92,10 @@ def default_precision(base):
 def program_rounds(files, w, *, chips: int, devices,
                    compute_dtype=None) -> list:
     """The program's lead rounds, driven as a run drives them."""
-    from bench import harness
+    from bench import families, harness
 
     cfg, traffic = files["config"], files["traffic"]
-    driver = harness.load_module(
-        harness.BENCH / "drivers" / f"{cfg['family']}.py",
-        f"bench_driver_{cfg['family']}")
+    driver = families.module(cfg["family"], "drivers")
     mesh = None
     if chips > 1:
         from repro.launch.mesh import make_client_mesh

@@ -2,16 +2,49 @@
 reference, and the traced run's per-layer metrics.
 
 Everything here is a plain function of the cell's files, so tests run it at
-a tiny width on the CPU. Nothing branches on a cell's name: the
-configuration's ``family`` names the program-side driver
-(``bench/drivers/<family>.py``) and the plain reference
-(``bench/references/<family>.py``), and each per-layer metric is a reader
-``bench/metrics/<name>.py``.
+a tiny width on the CPU. Nothing branches on a cell's name and nothing here
+reads a model's shape: each per-layer metric is a reader
+``bench/metrics/<name>.py``, and the configuration's ``family`` names the
+three files that hold all that the benchmark knows of a model family
+(``bench/families.py`` loads them by name). A family is added as these new
+files alone; no file the benchmark has is edited for it.
+
+``bench/drivers/<family>.py``, the system under test, built from the
+program's public constructors:
+
+- ``check_layout(cfg, stage, frozen, active, state)`` raises unless the
+  benchmark's weights have the program's layout and shapes;
+- ``System(cfg, traffic, frozen, state, pool_x, pool_y, seeds, mesh=None,
+  compute_dtype=None)``, with ``fill_cache()`` (the bytes it cached),
+  ``run_round(cohort, round_idx, params, state)`` (the new params and
+  state and the cohort's losses) and ``close()``.
+
+``bench/references/<family>.py``, the plain reference, which imports
+nothing of the program:
+
+- ``skew_classes(cfg)``: the number of classes (or topics) that the pool's
+  Dirichlet label skew is drawn over;
+- ``make_inputs(cfg, traffic, labels, key, chunk)``: the inputs of one
+  chunk of the pool's samples, ``[n, ...]`` of any shape and dtype, traced
+  under ``jax.jit``;
+- ``init_weights(cfg, stage, key)``: ``(frozen, active, state)``, traced
+  under ``jax.jit``;
+- ``batch_plan(n, batch, epochs, seed)`` and ``round_seed(client_seed,
+  round_idx)``: a client's minibatch indices, as the program draws them;
+- ``Reference(cfg, stage, lr=..., clip_norm=...)`` with ``round(frozen,
+  active, state, data, plans, weights)``: ``(active, state, losses)``, where
+  ``data[i]`` is client i's ``{"x": inputs, "y": labels}``;
+  ``bench/control.py`` plants its faults in its ``_local_train(frozen,
+  active, state, xs, ys)``, ``clients``, ``fold`` and ``_precision``;
+- ``tiny(cfg)``: the configuration cut to a size that the benchmark's CPU
+  tests run in seconds.
+
+``bench/counts/<family>.py``: ``flops_per_round(cfg, traffic)``, the
+counted FLOPs of one round by ``bench/flops.py``'s rule.
 """
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import time
 from pathlib import Path
@@ -21,8 +54,8 @@ import jax
 import numpy as np
 
 from bench import check, flops, trace as trace_mod, world
+from bench.families import BENCH, load_module, module
 
-BENCH = Path(__file__).resolve().parent
 GIB = float(2 ** 30)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a traced run measures its whole window untraced, as an untraced run
@@ -30,15 +63,6 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # of a round two- to threefold, and ten seconds of ResNet-18 rounds are
 # ~0.23 GB of device events
 TRACE_SECONDS = 3.0
-
-
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    if spec is None:
-        raise FileNotFoundError(path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def cell_files(root: Path, spec: Dict, workload: str) -> Dict:
@@ -98,38 +122,41 @@ def host_copy(tree):
     return jax.tree.map(lambda x: np.asarray(x, np.float32), tree)
 
 
-def make_pool(ref, cfg: Dict, labels: np.ndarray, seed: int) -> np.ndarray:
-    """The pool's images on the host, [clients, per_client, H, W, C], made
-    on the device in equal chunks of clients (one program)."""
+def make_pool(ref, cfg: Dict, traffic: Dict, labels: np.ndarray,
+              seed: int) -> np.ndarray:
+    """The pool's inputs on the host, [clients, per_client, ...] as the
+    family's ``make_inputs`` makes them, on the device in equal chunks of
+    clients (one program)."""
     n_clients, per = labels.shape
     chunk = max(d for d in range(1, min(n_clients, 10) + 1)
                 if n_clients % d == 0)
-    size, ch = cfg["image_size"], cfg["in_channels"]
-    out = np.empty((n_clients, per, size, size, ch), np.float32)
-    gen = jax.jit(lambda y, k, i: ref.make_images(cfg, y, k, i))
+    gen = jax.jit(lambda y, k, i: ref.make_inputs(cfg, traffic, y, k, i))
     key = jax.random.PRNGKey(seed)
+    out = None
     for i, lo in enumerate(range(0, n_clients, chunk)):
         y = labels[lo:lo + chunk].reshape(-1)
-        out[lo:lo + chunk] = np.asarray(gen(y, key, i)).reshape(
-            (chunk, per, size, size, ch))
+        x = np.asarray(gen(y, key, i))
+        if out is None:
+            out = np.empty((n_clients, per) + x.shape[1:], x.dtype)
+        out[lo:lo + chunk] = x.reshape((chunk, per) + x.shape[1:])
     return out
 
 
 class World:
     """What the seed makes for a cell, apart from the program: the pool's
-    labels and images, the client seeds, the weights and the cohorts."""
+    labels and inputs, the client seeds, the weights and the cohorts."""
 
     def __init__(self, files: Dict, seed: int):
         cfg, traffic = files["config"], files["traffic"]
         self.cfg, self.traffic = cfg, traffic
-        self.ref = load_module(BENCH / "references" / f"{cfg['family']}.py",
-                               f"bench_ref_{cfg['family']}")
+        self.ref = module(cfg["family"], "references")
         streams = world.streams(seed)
         n_clients, per = traffic["clients"], traffic["samples_per_client"]
-        self.labels = world.label_skew(n_clients, per, cfg["num_classes"],
+        self.labels = world.label_skew(n_clients, per,
+                                       self.ref.skew_classes(cfg),
                                        traffic["alpha"], streams["labels"])
-        self.pool_x = make_pool(self.ref, cfg, self.labels,
-                                world.jax_seed(streams["images"]))
+        self.pool_x = make_pool(self.ref, cfg, traffic, self.labels,
+                                world.jax_seed(streams["inputs"]))
         self.seeds = world.client_seeds(n_clients, streams["clients"])
         self.frozen, self.active, self.state = jax.jit(
             lambda k: self.ref.init_weights(cfg, traffic["stage"], k))(
@@ -177,25 +204,30 @@ def run_cell(files: Dict, *, seed: int, seconds: float, chips: int,
     its limit under ``checks``, and ``notes`` for the log."""
     cfg, traffic, limits = files["config"], files["traffic"], files["limits"]
     devices = list(devices if devices is not None else jax.devices())[:chips]
-    driver = load_module(BENCH / "drivers" / f"{cfg['family']}.py",
-                         f"bench_driver_{cfg['family']}")
+    driver = module(cfg["family"], "drivers")
     mesh = None
     if chips > 1:
         from repro.launch.mesh import make_client_mesh
         mesh = make_client_mesh(chips, devices=devices)
 
     # ----- set-up: world, engine, cache, the lead rounds -----
+    # where set-up's seconds go, for the log: start-up and imports, the
+    # world, the engine with its cache, the lead rounds
+    marks = [time.perf_counter()]
     w = World(files, seed)
     driver.check_layout(cfg, traffic["stage"], w.frozen, w.active, w.state)
+    marks.append(time.perf_counter())
     system = driver.System(cfg, traffic, w.frozen, w.state, w.pool_x,
                            w.labels, w.seeds, mesh=mesh)
     cache_bytes = system.fill_cache()
+    marks.append(time.perf_counter())
     params, state = w.active, w.state
     del w.frozen, w.active, w.state
     lead: List = []
     for r, cohort in enumerate(w.lead_cohorts):
         params, state, losses = system.run_round(cohort, r, params, state)
         lead.append(host_copy((params, state)) + (losses,))
+    marks.append(time.perf_counter())
 
     # ----- the measured window -----
     counter = CompileCounter()
@@ -275,6 +307,8 @@ def run_cell(files: Dict, *, seed: int, seconds: float, chips: int,
     notes = {"rounds": n_rounds, "window_s": window_s,
              "round_ms_median": float(np.median(walls)) * 1e3,
              "cache_bytes": cache_bytes,
+             "setup_split_s": [round(b - a, 3) for a, b in
+                               zip([t_start] + marks, marks)],
              "compiles_in_window": counter.count,
              "leaves_compared": numbers["leaves_compared"],
              # every number the comparison has, compared or not

@@ -31,6 +31,16 @@ import numpy as np
 BN_EPS = 1e-5
 NOISE = 0.35        # pixel noise around each class prototype
 PROTO_CELLS = 8     # prototypes are 8x8 patterns, upsampled to the image
+# the benchmark's CPU tests: published depth, toy width, so that every
+# block, stride and projection is there
+TINY_CHANNELS = {"resnet": [4, 8, 8, 8], "vgg": [4, 8, 8, 8, 8]}
+TINY_IMAGE = {"resnet": 8, "vgg": 32}
+
+
+def tiny(cfg: Dict) -> Dict:
+    """``cfg`` cut to a size the CPU runs in seconds."""
+    return dict(cfg, stage_channels=TINY_CHANNELS[cfg["kind"]],
+                image_size=TINY_IMAGE[cfg["kind"]], num_classes=4)
 
 
 # ---------------------------------------------------------------------------
@@ -38,7 +48,13 @@ PROTO_CELLS = 8     # prototypes are 8x8 patterns, upsampled to the image
 # ---------------------------------------------------------------------------
 
 
-def make_images(cfg: Dict, labels: jnp.ndarray, key, chunk) -> jnp.ndarray:
+def skew_classes(cfg: Dict) -> int:
+    """The classes the pool's label skew is drawn over."""
+    return cfg["num_classes"]
+
+
+def make_inputs(cfg: Dict, traffic: Dict, labels: jnp.ndarray, key, chunk
+                ) -> jnp.ndarray:
     """Images for ``labels``, the ``chunk``-th slice of the pool: a
     low-frequency prototype per class (the same in every chunk) plus
     Gaussian pixel noise, [n, size, size, channels] float32."""

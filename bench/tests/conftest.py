@@ -18,15 +18,14 @@ for p in (str(ROOT), str(ROOT / "src")):
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [(w["name"], w["chips"]) for w in SPEC["workloads"]]
 
-# published depth, toy width: every block, stride and projection is there
-TINY_CHANNELS = {"resnet": [4, 8, 8, 8], "vgg": [4, 8, 8, 8, 8]}
-TINY_IMAGE = {"resnet": 8, "vgg": 32}
-
 
 def tiny_checkout(dest: Path) -> Path:
     """A copy of ``BENCHMARK.json`` and ``bench/`` whose configurations and
     traffic are cut to a size the CPU runs in seconds, with ``src`` linked
-    in. Cells, names and limits are the real ones."""
+    in: each configuration by its family's ``tiny``. Cells, names and
+    limits are the real ones."""
+    from bench import families
+
     shutil.copytree(ROOT / "bench", dest / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", ".trace",
                                                   "tests"))
@@ -35,10 +34,8 @@ def tiny_checkout(dest: Path) -> Path:
     for c in SPEC["configs"]:
         path = dest / c["file"]
         cfg = json.loads(path.read_text())
-        cfg["stage_channels"] = TINY_CHANNELS[cfg["kind"]]
-        cfg["image_size"] = TINY_IMAGE[cfg["kind"]]
-        cfg["num_classes"] = 4
-        path.write_text(json.dumps(cfg))
+        ref = families.module(cfg["family"], "references")
+        path.write_text(json.dumps(ref.tiny(cfg)))
     chips = {w["traffic"]: w["chips"] for w in SPEC["workloads"]}
     for t in (dest / "bench" / "traffic").glob("*.json"):
         traffic = json.loads(t.read_text())

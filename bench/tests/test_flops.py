@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import pytest
 
 from conftest import ROOT
-from bench import flops
+from bench import families
+
+flops = families.module("cnn", "counts")
 
 RESNET18 = json.loads((ROOT / "bench/configs/resnet18.json").read_text())
 VGG16 = json.loads((ROOT / "bench/configs/vgg16_bn.json").read_text())

@@ -17,7 +17,7 @@ MAX_CLIENT_SEED = 40_000
 
 def streams(seed: int) -> Dict[str, np.random.SeedSequence]:
     """Independent seed streams for each part of the world."""
-    names = ("weights", "images", "labels", "clients", "cohorts")
+    names = ("weights", "inputs", "labels", "clients", "cohorts")
     return dict(zip(names, np.random.SeedSequence(seed).spawn(len(names))))
 
 
