@@ -35,6 +35,9 @@ SHAPES = {s.name: s for s in (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)}
 # Architecture configs
 # ---------------------------------------------------------------------------
 
+# published ``layer_types`` names -> the layer kinds of models/transformer.py
+LAYER_TYPE_KINDS = {"mamba": "mamba2_mlp", "attention": "attn_mlp"}
+
 
 @dataclass(frozen=True)
 class ArchConfig:
@@ -57,6 +60,8 @@ class ArchConfig:
     attention_impl: str = "xla"
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"  # rope | none (granite's NoPE layers)
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head_dim)
 
     # --- MLA (deepseek-v2 / minicpm3) ---
     q_lora_rank: int = 0
@@ -80,11 +85,16 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_kernel: int = 4
+    ssm_chunk: int = 256  # SSD chunk length (Mamba-2's chunk_size)
     slstm_every: int = 0  # xlstm: every k-th layer is sLSTM (0 = none)
 
     # --- hybrid (zamba2) ---
     attn_every: int = 0  # every k-th layer is a (shared) attention layer
     num_shared_attn_sets: int = 0  # weight-tied attention block sets
+    # --- explicit layer pattern (granite-4.0-h): the published
+    # ``layer_types`` ("mamba" | "attention"), read for the first num_layers;
+    # each mixer is followed by the MLP ---
+    layer_types: tuple = ()
 
     # --- encoder-only / modality ---
     is_encoder_only: bool = False
@@ -97,6 +107,11 @@ class ArchConfig:
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # granite's scalings: h = embedding_multiplier * E[tokens]; each residual
+    # branch times residual_multiplier; logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # --- SmartFreeze / progressive training ---
     num_freeze_blocks: int = 4
@@ -128,6 +143,9 @@ class ArchConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer kind string, length num_layers."""
+        if self.layer_types:
+            return tuple(LAYER_TYPE_KINDS[t]
+                         for t in self.layer_types[:self.num_layers])
         kinds = []
         for i in range(self.num_layers):
             if self.family == "ssm":  # xlstm
@@ -208,6 +226,8 @@ class ArchConfig:
             small.update(ssm_state=16, ssm_head_dim=16)
         if self.attn_every:
             small.update(attn_every=2)
+        if self.layer_types:  # through the first attention layer
+            small.update(num_layers=self.layer_types.index("attention") + 1)
         if self.slstm_every:
             small.update(slstm_every=4)
         if self.modality == "vision_stub":
@@ -255,7 +275,7 @@ def _load_all():
     from repro.configs import (  # noqa: F401
         xlstm_350m, deepseek_v2_236b, grok1_314b, minicpm3_4b, llama3_8b,
         qwen2_72b, deepseek_coder_33b, internvl2_2b, hubert_xlarge, zamba2_7b,
-        resnet_cifar, vgg_cifar,
+        granite_4_0_h_micro, resnet_cifar, vgg_cifar,
     )
 
 

@@ -15,7 +15,10 @@ stop_gradient.
 
 ``make_fed_round_step`` wraps the stage step into a federated round: pods are
 cross-silo clients — broadcast, K local steps (lax.scan), dataset-weighted
-parameter aggregation over the pod dimension (Eq. 1).
+parameter aggregation over the pod dimension (Eq. 1). ``lm_round_loss_fn``
+gives the same stage loss in ``fl/engine.RoundEngine``'s signature, so an LM
+stage trains in the fused round the CNNs run, on each client's own token
+rows.
 """
 from __future__ import annotations
 
@@ -36,6 +39,8 @@ class StagePlan(NamedTuple):
     stage: int
     lo: int
     hi: int
+    # the embedding is in the active tree: at stage 0, and at the last stage
+    # when it is tied to the head (it then trains through the head alone)
     train_embed: bool
     final: bool  # last stage: real final_norm + head instead of output module
     # execution order: list of (region, kind, seg_idx, a, b) with a/b relative
@@ -54,7 +59,7 @@ def make_stage_plan(cfg: ArchConfig, stage: Optional[int]) -> StagePlan:
         bounds = cfg.block_boundaries()
         lo, hi = bounds[stage], bounds[stage + 1]
         final = stage == T - 1
-        train_embed = stage == 0
+        train_embed = stage == 0 or (final and cfg.tie_embeddings)
     runs = []
     pos = 0
     for si, (kind, n) in enumerate(cfg.segments()):
@@ -195,23 +200,26 @@ def stage_prefix_features(model: LM, frozen: Params, active: Params,
     region is layers [0, lo) and the active region [lo, hi)), so the prefix
     is a clean split point. When ``prefix_is_static(plan)`` the result is a
     pure function of the batch and can be cached across the stage's rounds
-    — the round engine (fl/engine.py) exploits exactly that."""
+    — the round engine (fl/engine.py) exploits exactly that. Its ops carry
+    the device scope ``prefix``."""
     from repro.dist.sharding import shard_batch
 
     cfg = model.cfg
     src = active if plan.train_embed else frozen
-    h = shard_batch(model.embed(src, batch), batch_axes=cfg.batch_axes)
-    aux_total = jnp.float32(0.0)
-    for ri, (region, kind, si, a, b) in enumerate(plan.runs):
-        if region == "active":
-            break
-        if kind == "shared_attn":
-            sp = active["shared_attn"][str(_shared_idx(model, si))]
-            h, aux = layer_apply(sp, h, cfg, kind, causal=not cfg.is_encoder_only)
-        else:
-            h, aux = _run(model, h, frozen["runs"][str(ri)], kind, cfg,
-                          remat=False)
-        aux_total = aux_total + aux
+    with jax.named_scope("prefix"):
+        h = shard_batch(model.embed(src, batch), batch_axes=cfg.batch_axes)
+        aux_total = jnp.float32(0.0)
+        for ri, (region, kind, si, a, b) in enumerate(plan.runs):
+            if region == "active":
+                break
+            if kind == "shared_attn":
+                sp = active["shared_attn"][str(_shared_idx(model, si))]
+                h, aux = layer_apply(sp, h, cfg, kind,
+                                     causal=not cfg.is_encoder_only)
+            else:
+                h, aux = _run(model, h, frozen["runs"][str(ri)], kind, cfg,
+                              remat=False)
+            aux_total = aux_total + aux
     return h, aux_total
 
 
@@ -219,10 +227,12 @@ def stage_forward_from_features(model: LM, active: Params, h, aux_total,
                                 plan: StagePlan, *, remat: bool = True,
                                 remat_policy=None):
     """Active-suffix forward from (possibly cached) prefix features. Applies
-    the stop-gradient memory boundary, the active runs, and the final-norm
-    head or output module. Returns (hidden, head_w, aux_loss)."""
+    the stop-gradient memory boundary (where layers are frozen: at stage 0
+    the embedding trains through its lookup), the active runs, and the
+    final-norm head or output module. Returns (hidden, head_w, aux_loss)."""
     cfg = model.cfg
-    h = jax.lax.stop_gradient(h)  # memory boundary: no bwd into prefix
+    if plan.lo > 0:
+        h = jax.lax.stop_gradient(h)  # memory boundary: no bwd into prefix
     for ri, (region, kind, si, a, b) in enumerate(plan.runs):
         if region != "active":
             continue
@@ -283,6 +293,24 @@ def stage_loss_fn(model: LM, plan: StagePlan, *, remat: bool = True,
     return loss_fn
 
 
+def lm_round_loss_fn(model: LM, plan: StagePlan):
+    """The stage loss in ``RoundEngine``'s signature ``(active, frozen,
+    state, batch) -> (loss, state)``: ``batch["x"]`` holds a client's token
+    rows [b, S], each position predicts the next token (the last predicts
+    none), and ``state`` is passed through (an LM holds no batch-norm
+    state)."""
+    loss_fn = stage_loss_fn(model, plan)
+
+    def fn(active: Params, frozen: Params, state, batch: Dict):
+        tokens = batch["x"]
+        labels = jnp.concatenate(
+            [tokens[:, 1:], jnp.full_like(tokens[:, :1], -1)], axis=1)
+        return loss_fn(active, frozen, {"tokens": tokens,
+                                        "labels": labels}), state
+
+    return fn
+
+
 def cached_stage_loss_fn(model: LM, plan: StagePlan, *, remat: bool = True,
                          remat_policy=None):
     """Stage loss over cached prefix features: the batch carries ``h0`` (the
@@ -301,7 +329,7 @@ def init_stage_active(model: LM, params: Params, plan: StagePlan, rng) -> Tuple[
     """(frozen, active) with a freshly-initialized output module when needed."""
     frozen, active = split_stage_params(model, params, plan)
     if not plan.final:
-        fac = PFac(rng, dtype=jnp.bfloat16)
+        fac = PFac(rng, dtype=jnp.dtype(model.cfg.param_dtype))
         active["op"] = op_mod.lm_op_init(fac.sub("op"), model.cfg, plan.stage)
     return frozen, active
 

@@ -138,6 +138,9 @@ def layer_activation_bytes(cfg, batch: int, seq: int, kind: str) -> int:
         return (proj + states + tok * d) * b
     if kind == "slstm":
         return (tok * 4 * d + tok * d) * b
+    if kind == "mamba2_mlp":
+        return (layer_activation_bytes(cfg, batch, seq, "mamba2")
+                + (tok * cfg.d_ff * 2 + tok * d) * b)
     raise ValueError(kind)
 
 
@@ -274,6 +277,9 @@ def layer_fwd_flops_per_token(cfg, kind: str, seq: int) -> float:
         proj = 2 * d * (2 * di + 2 * cfg.ssm_state + H) + 2 * di * d
         scan = 2 * 3 * di * cfg.ssm_state  # state update + readout
         return proj + scan
+    if kind == "mamba2_mlp":
+        return (layer_fwd_flops_per_token(cfg, "mamba2", seq)
+                + 2 * 3 * d * cfg.d_ff)
     if kind == "mlstm":
         di = cfg.ssm_expand * d
         proj = 2 * d * 2 * di + 2 * 3 * di * di + 2 * di * d

@@ -147,6 +147,12 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return outs.transpose(1, 0, 2, 3, 4).reshape(B, S, H, dv)
 
 
+def gqa_scale(cfg: ArchConfig) -> float:
+    """The softmax scale: the config's ``attention_multiplier``, else
+    1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or 1.0 / float(np.sqrt(cfg.head_dim))
+
+
 def gqa_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig, *,
                 positions: Optional[jnp.ndarray] = None,
                 causal: bool = True) -> jnp.ndarray:
@@ -158,15 +164,16 @@ def gqa_forward(p: Params, x: jnp.ndarray, cfg: ArchConfig, *,
     q = _split_heads(dense(p["wq"], x), nq)
     k = _split_heads(dense(p["wk"], x), nkv)
     v = _split_heads(dense(p["wv"], x), nkv)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     g = nq // nkv
     # broadcast kv across the q-head group (repeat keeps the head dim = nq so
     # the "heads" sharding axis stays consistent through the whole layer)
     k = _shard_heads(jnp.repeat(k, g, axis=2), batch_axes=cfg.batch_axes)
     v = _shard_heads(jnp.repeat(v, g, axis=2), batch_axes=cfg.batch_axes)
     q = _shard_heads(q, batch_axes=cfg.batch_axes)
-    scale = 1.0 / float(np.sqrt(hd))
+    scale = gqa_scale(cfg)
     if getattr(cfg, "attention_impl", "xla") == "pallas":
         # kernels/flash_attention.py via its differentiable ops wrapper
         # (custom_vjp, recompute backward through the XLA reference). kv is
@@ -201,13 +208,16 @@ def gqa_decode(p: Params, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
     g = nq // nkv
     S = cache["k"].shape[1]
     positions = jnp.full((B, 1), pos, jnp.int32)
-    q = apply_rope(_split_heads(dense(p["wq"], x), nq), positions, cfg.rope_theta)
-    k_new = apply_rope(_split_heads(dense(p["wk"], x), nkv), positions, cfg.rope_theta)
+    q = _split_heads(dense(p["wq"], x), nq)
+    k_new = _split_heads(dense(p["wk"], x), nkv)
+    if cfg.position_embedding == "rope":
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k_new = apply_rope(k_new, positions, cfg.rope_theta)
     v_new = _split_heads(dense(p["wv"], x), nkv)
     k = jax.lax.dynamic_update_slice(cache["k"], k_new.astype(cache["k"].dtype), (0, pos, 0, 0))
     v = jax.lax.dynamic_update_slice(cache["v"], v_new.astype(cache["v"].dtype), (0, pos, 0, 0))
     qg = q.reshape(B, 1, nkv, g, hd)
-    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32) / jnp.sqrt(hd).astype(jnp.float32)
+    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32) * gqa_scale(cfg)
     valid = (jnp.arange(S) <= pos)[None, None, None, None, :]
     scores = jnp.where(valid, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)

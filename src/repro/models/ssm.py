@@ -78,7 +78,8 @@ def mamba2_forward(p: Params, u: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     A = -jnp.exp(p["A_log"])  # [H], negative
     log_a = (dt * A).astype(jnp.float32)  # [B,S,H] log decay per step
 
-    y = _ssd_chunked(x, Bm, Cm, dt, log_a, chunk=min(SSM_CHUNK, S))
+    with jax.named_scope("ssd"):
+        y = _ssd_chunked(x, Bm, Cm, dt, log_a, chunk=min(cfg.ssm_chunk, S))
     y = y + (p["D"][:, None] * x.astype(jnp.float32)).astype(y.dtype)
     y = y.reshape(Bsz, S, d_inner)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)

@@ -1,7 +1,8 @@
 """Generic LM assembly: embed → segments of scanned homogeneous layers → head.
 
-Layer kinds: attn_mlp (dense), attn_moe, mamba2, mlstm, slstm, shared_attn
-(zamba2's weight-tied attention blocks — 2 alternating sets).
+Layer kinds: attn_mlp (dense), attn_moe, mamba2, mamba2_mlp (granite's
+Mamba-2 mixer followed by the MLP), mlstm, slstm, shared_attn (zamba2's
+weight-tied attention blocks — 2 alternating sets).
 
 The class exposes the decomposed interface SmartFreeze's progressive trainer
 needs: ``embed`` / ``run_layers(lo, hi)`` / ``head``, where run_layers slices
@@ -43,9 +44,20 @@ def mlp_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     return dense(p["down"], act(dense(p["gate"], x)) * dense(p["up"], x))
 
 
+def _residual(y: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
+    """A residual branch scaled by ``cfg.residual_multiplier``."""
+    r = cfg.residual_multiplier
+    return y if r == 1.0 else y * jnp.asarray(r, y.dtype)
+
+
 def layer_init(fac: PFac, cfg: ArchConfig, kind: str) -> Params:
     p: Params = {}
-    if kind in ("attn_mlp", "attn_moe", "shared_attn"):
+    if kind == "mamba2_mlp":
+        p["ln1"] = norm_init(fac, "ln1", cfg.d_model, cfg.norm)
+        p["mix"] = ssm_mod.mamba2_init(fac.sub("mix"), cfg)
+        p["ln2"] = norm_init(fac, "ln2", cfg.d_model, cfg.norm)
+        p["mlp"] = mlp_init(fac.sub("mlp"), cfg, cfg.d_ff)
+    elif kind in ("attn_mlp", "attn_moe", "shared_attn"):
         p["ln1"] = norm_init(fac, "ln1", cfg.d_model, cfg.norm)
         p["attn"] = attn.attn_init(fac.sub("attn"), cfg)
         p["ln2"] = norm_init(fac, "ln2", cfg.d_model, cfg.norm)
@@ -71,15 +83,21 @@ def layer_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
                 causal: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-seq layer. Returns (y, aux_loss)."""
     aux = jnp.float32(0.0)
+    if kind == "mamba2_mlp":
+        h = x + _residual(ssm_mod.mamba2_forward(
+            p["mix"], norm(p["ln1"], x, cfg.norm, cfg.norm_eps), cfg), cfg)
+        y = mlp_apply(p["mlp"], norm(p["ln2"], h, cfg.norm, cfg.norm_eps), cfg)
+        return h + _residual(y, cfg), aux
     if kind in ("attn_mlp", "attn_moe", "shared_attn"):
-        h = x + attn.attn_forward(p["attn"], norm(p["ln1"], x, cfg.norm, cfg.norm_eps),
-                                  cfg, causal=causal)
+        h = x + _residual(attn.attn_forward(
+            p["attn"], norm(p["ln1"], x, cfg.norm, cfg.norm_eps), cfg,
+            causal=causal), cfg)
         hn = norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
         if kind == "attn_moe":
             y, aux = moe_mod.moe_forward(p["moe"], hn, cfg)
         else:
             y = mlp_apply(p["mlp"], hn, cfg)
-        return h + y, aux
+        return h + _residual(y, cfg), aux
     # ssm/recurrent kinds
     fn = {"mamba2": ssm_mod.mamba2_forward, "mlstm": ssm_mod.mlstm_forward,
           "slstm": ssm_mod.slstm_forward}[kind]
@@ -89,22 +107,30 @@ def layer_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig, kind: str, *,
 def layer_init_cache(cfg: ArchConfig, kind: str, batch: int, max_seq: int, dtype):
     if kind in ("attn_mlp", "attn_moe", "shared_attn"):
         return attn.attn_init_cache(cfg, batch, max_seq, dtype)
-    fn = {"mamba2": ssm_mod.mamba2_init_state, "mlstm": ssm_mod.mlstm_init_state,
+    fn = {"mamba2": ssm_mod.mamba2_init_state,
+          "mamba2_mlp": ssm_mod.mamba2_init_state,
+          "mlstm": ssm_mod.mlstm_init_state,
           "slstm": ssm_mod.slstm_init_state}[kind]
     return fn(cfg, batch, dtype)
 
 
 def layer_decode(p: Params, x: jnp.ndarray, cache, pos, cfg: ArchConfig, kind: str):
+    if kind == "mamba2_mlp":
+        a, cache = ssm_mod.mamba2_step(
+            p["mix"], norm(p["ln1"], x, cfg.norm, cfg.norm_eps), cache, cfg)
+        h = x + _residual(a, cfg)
+        y = mlp_apply(p["mlp"], norm(p["ln2"], h, cfg.norm, cfg.norm_eps), cfg)
+        return h + _residual(y, cfg), cache
     if kind in ("attn_mlp", "attn_moe", "shared_attn"):
         a, cache = attn.attn_decode(p["attn"], norm(p["ln1"], x, cfg.norm, cfg.norm_eps),
                                     cache, pos, cfg)
-        h = x + a
+        h = x + _residual(a, cfg)
         hn = norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
         if kind == "attn_moe":
             y = moe_mod.moe_decode(p["moe"], hn, cfg)
         else:
             y = mlp_apply(p["mlp"], hn, cfg)
-        return h + y, cache
+        return h + _residual(y, cfg), cache
     fn = {"mamba2": ssm_mod.mamba2_step, "mlstm": ssm_mod.mlstm_step,
           "slstm": ssm_mod.slstm_step}[kind]
     y, cache = fn(p["mix"], norm(p["ln"], x, cfg.norm, cfg.norm_eps), cache, cfg)
@@ -212,6 +238,8 @@ class LM:
                          batch["frames"].astype(_dt(cfg.compute_dtype)))
         tok = params["embed"]
         h = tok[batch["tokens"]].astype(_dt(cfg.compute_dtype))
+        if cfg.embedding_multiplier != 1.0:
+            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
         if cfg.modality == "vision_stub" and "patches" in batch:
             fp = params["frontend"]
             pe = dense(fp["proj2"], jax.nn.gelu(
@@ -248,8 +276,10 @@ class LM:
         cfg = self.cfg
         h = norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         if cfg.tie_embeddings:
-            return h @ params["embed"].T.astype(h.dtype)
-        return dense(params["head"], h)
+            logits = h @ params["embed"].T.astype(h.dtype)
+        else:
+            logits = dense(params["head"], h)
+        return scale_logits(logits, cfg)
 
     def forward(self, params: Params, batch: Dict) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Full forward. Returns (logits, aux_loss)."""
@@ -293,8 +323,7 @@ class LM:
         from repro.dist.sharding import shard_batch
 
         cfg = self.cfg
-        h = shard_batch(params["embed"][batch["tokens"]].astype(_dt(cfg.compute_dtype)),
-                        batch_axes=cfg.batch_axes)
+        h = shard_batch(self.embed(params, batch), batch_axes=cfg.batch_axes)
         new_caches = {}
         for kind, si, s_lo, s_hi in self._seg_table():
             if kind == "shared_attn":
@@ -314,6 +343,13 @@ class LM:
 
 def _dt(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
+
+
+def scale_logits(logits: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
+    """Logits divided by ``cfg.logits_scaling`` (granite's 8)."""
+    if cfg.logits_scaling == 1.0:
+        return logits
+    return logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
 
 
 def token_loss(logits: jnp.ndarray, batch: Dict, cfg: ArchConfig) -> jnp.ndarray:
@@ -375,7 +411,8 @@ def chunked_ce_loss(h: jnp.ndarray, head_w: jnp.ndarray, batch: Dict,
 
     @jax.checkpoint
     def chunk_loss(h_c, y_c):
-        logits = (h_c @ head_w.astype(h_c.dtype)).astype(jnp.float32)
+        logits = scale_logits((h_c @ head_w.astype(h_c.dtype))
+                              .astype(jnp.float32), cfg)
         if spec is not None:
             logits = jax.lax.with_sharding_constraint(logits, spec)
         logz = jax.scipy.special.logsumexp(logits, axis=-1)

@@ -10,7 +10,8 @@ import pytest
 from repro import configs
 from repro.models.transformer import build
 
-CASES = ["llama3-8b", "minicpm3-4b", "xlstm-350m", "zamba2-7b", "grok-1-314b"]
+CASES = ["llama3-8b", "minicpm3-4b", "xlstm-350m", "zamba2-7b", "grok-1-314b",
+         "granite-4.0-h-micro"]
 
 
 @pytest.mark.parametrize("arch", CASES)
